@@ -29,6 +29,7 @@ import numpy as np
 from benchmarks.common import emit
 from repro.config import ForestConfig
 from repro.data.tabular import synthetic_resource_dataset
+from repro.launch.mesh import make_mesh
 from repro.tabgen import extend_artifacts, fit_artifacts
 
 #: static workload identity — check_bench matches records on ``config``
@@ -41,14 +42,13 @@ _FIELDS = ("feat", "thr_val", "leaf", "best_round", "val_curve")
 
 
 def main(quick: bool = True, json_path: str = None) -> None:
-    import jax
     cfg = QUICK if quick else FULL
     X, y = synthetic_resource_dataset(cfg["n"], cfg["p"], cfg["n_y"], seed=0)
     mk = lambda r: ForestConfig(n_t=cfg["n_t"], duplicate_k=cfg["dup_k"],  # noqa: E731
                                 n_trees=r, max_depth=4, n_bins=32,
                                 reg_lambda=1.0)
     total = cfg["base_trees"] + cfg["extra_trees"]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     base = fit_artifacts(X, y, mk(cfg["base_trees"]), seed=0, mesh=mesh)
 
     def warm():

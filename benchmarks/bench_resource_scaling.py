@@ -64,9 +64,6 @@ def main(sizes=(200, 500, 1000), p=8, n_y=2, n_t=3, K=10, T=10) -> None:
                                           p=p, n_y=n_y, n_t=n_t, K=K, T=T,
                                           mo=mo, es=es)
             res = run_measured(snippet, timeout=1200)
-            if res.get("error"):
-                emit(f"resource_scaling/{name}/n={n}", "fail", "fail")
-                continue
             us = res["wall_s"] * 1e6
             mib = res["peak_rss_bytes"] / 2 ** 20
             emit(f"resource_scaling/{name}/n={n}", f"{us:.0f}", f"{mib:.1f}")
@@ -82,6 +79,7 @@ import numpy as np
 import jax
 from repro.config import ForestConfig
 from repro.data.tabular import synthetic_resource_batches
+from repro.launch.mesh import make_mesh
 from repro.tabgen import fit_artifacts
 
 n, p, n_y, arm = {n}, {p}, {n_y}, {arm!r}
@@ -109,7 +107,7 @@ else:
     # the data path), except the padded reference arm: the default
     # single-device route with dense [n_y, n_max, p] class blocks
     mesh = (None if arm == "in_memory_padded"
-            else jax.make_mesh((1, 1), ("data", "model")))
+            else make_mesh((1, 1)))
 walls = []
 for _ in range({reps}):
     t0 = time.perf_counter()
@@ -141,8 +139,7 @@ def main_store(quick: bool = True, json_path: str | None = None,
     # full sizes are bounded by hosted-runner RAM: the quick trajectory
     # measures ~12 KiB RSS/row for the gated arms (XLA temps scale with n),
     # so 524288 rows ~ 6 GiB — comfortably inside a 16 GB nightly runner,
-    # while anything million-row would OOM all three arms into error
-    # records and fail the gate by construction
+    # while anything million-row would OOM its child and fail the run
     sizes = sizes or ((16384, 131072) if quick else (262144, 524288))
     records = []
     for n in sizes:
@@ -150,13 +147,6 @@ def main_store(quick: bool = True, json_path: str | None = None,
         for arm in ("in_memory", "store", "store", "in_memory"):   # ABBA
             runs[arm].append(_ooc_run(arm, n, p, n_y, fit_cfg, reps=2))
         for arm, res_list in runs.items():
-            errs = [r["error"] for r in res_list if r.get("error")]
-            if errs:
-                emit(f"store_scaling/{arm}/n={n}", "fail", "fail")
-                records.append({"config": {"workload": "store_scaling",
-                                           "arm": arm, "n": n, "p": p},
-                                "error": errs[0]})
-                continue
             wall = min(r["fit_wall_s"] for r in res_list)
             n_ens = res_list[0]["n_ens"]
             rss = max(r["peak_rss_bytes"] for r in res_list)
@@ -188,28 +178,22 @@ def main_store(quick: bool = True, json_path: str | None = None,
     # point, not the absolute n
     n_ref = sizes[-1] if quick else sizes[0]
     res = _ooc_run("in_memory_padded", n_ref, p, n_y, fit_cfg, reps=1)
-    if res.get("error"):
-        emit(f"store_scaling/in_memory_padded/n={n_ref}", "fail", "fail")
-        records.append({"config": {"workload": "store_scaling",
-                                   "arm": "in_memory_padded", "n": n_ref,
-                                   "p": p}, "error": res["error"]})
-    else:
-        records.append({
-            "config": {"workload": "store_scaling", "arm": "in_memory_padded",
-                       "n": n_ref, "p": p, "n_y": n_y,
-                       **{k: fit_cfg[k] for k in ("n_t", "K", "T", "mo")}},
-            "devices": 1,
-            "trainer": "single_padded",
-            "fit_wall_s": res["fit_wall_s"],
-            "includes_compile": True,
-            "padded_coldstart_rows_per_sec": n_ref * res["n_ens"]
-            / res["fit_wall_s"],
-            "peak_rss_bytes": res["peak_rss_bytes"],
-            "dataset_bytes": n_ref * p * 4,
-        })
-        emit(f"store_scaling/in_memory_padded/n={n_ref}",
-             f"{res['fit_wall_s'] * 1e6:.0f}",
-             f"{res['peak_rss_bytes'] / 2**20:.1f}")
+    records.append({
+        "config": {"workload": "store_scaling", "arm": "in_memory_padded",
+                   "n": n_ref, "p": p, "n_y": n_y,
+                   **{k: fit_cfg[k] for k in ("n_t", "K", "T", "mo")}},
+        "devices": 1,
+        "trainer": "single_padded",
+        "fit_wall_s": res["fit_wall_s"],
+        "includes_compile": True,
+        "padded_coldstart_rows_per_sec": n_ref * res["n_ens"]
+        / res["fit_wall_s"],
+        "peak_rss_bytes": res["peak_rss_bytes"],
+        "dataset_bytes": n_ref * p * 4,
+    })
+    emit(f"store_scaling/in_memory_padded/n={n_ref}",
+         f"{res['fit_wall_s'] * 1e6:.0f}",
+         f"{res['peak_rss_bytes'] / 2**20:.1f}")
     if json_path:
         payload = {
             "bench": "resource_scaling",

@@ -13,6 +13,11 @@ def run_measured(snippet: str, timeout: int = 900,
     """Run a python snippet in a subprocess; returns its printed JSON plus
     wall time and peak RSS (KiB->bytes). Each config gets a clean process so
     peak memory is per-config (ru_maxrss is monotonic within a process).
+    A child that fails raises here, failing its section and the run.
+
+    The calling process must not have touched a device yet: an accelerator
+    belongs to one process at a time, so a parent holding it would leave
+    the child to fail or hang.
 
     ``env_extra`` adds/overrides env vars — e.g. ``XLA_FLAGS`` to set a
     virtual device count, which must be in place before jax initialises."""
@@ -26,7 +31,8 @@ def run_measured(snippet: str, timeout: int = 900,
         "resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024\n"
         "print('\\n@@RESULT@@' + json.dumps(out))\n"
     )
-    env = {"PYTHONPATH": "src", "HOME": "/root", "PATH": "/usr/bin:/bin"}
+    env = {"PYTHONPATH": "src", "HOME": os.environ.get("HOME", "."),
+           "PATH": "/usr/bin:/bin"}
     if "JAX_PLATFORMS" in os.environ:    # scrubbed env: keep platform pin
         env["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]
     if env_extra:
@@ -35,12 +41,12 @@ def run_measured(snippet: str, timeout: int = 900,
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
     if proc.returncode != 0:
-        return {"error": proc.stderr[-1500:], "wall_s": None,
-                "peak_rss_bytes": None}
+        raise RuntimeError(f"benchmark child exited {proc.returncode}:\n"
+                           + proc.stderr[-1500:])
     for line in proc.stdout.splitlines():
         if line.startswith("@@RESULT@@"):
             return json.loads(line[len("@@RESULT@@"):])
-    return {"error": "no result line", "wall_s": None, "peak_rss_bytes": None}
+    raise RuntimeError("benchmark child printed no result line")
 
 
 def emit(name: str, us_per_call, derived):

@@ -16,11 +16,12 @@ import numpy as np
 from repro.config import ForestConfig
 from repro.forest.distributed import make_distributed_fit
 from repro.forest.packed import PackedForest, predict_forest
+from repro.launch.mesh import make_mesh
 
 
 def main():
     print(f"devices: {len(jax.devices())}")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2))
     rng = np.random.default_rng(0)
     n, p = 1024, 6
     mu = rng.normal(size=p).astype(np.float32)

@@ -1,9 +1,9 @@
 """Gradient/count histogram accumulation — the XGBoost ``hist`` hot spot.
 
-``build_histogram`` is the pure-jnp implementation (segment-sum per feature).
-On TPU the Pallas kernel in ``repro/kernels/hist`` implements the same
-contract as a one-hot MXU matmul; ``repro.kernels.hist.ops.histogram``
-dispatches between them.
+``build_histogram`` picks, by ``impl``, between the pure-jnp reference
+(segment-sum per feature, ``repro/kernels/hist/ref.py``) and the Pallas
+kernel in ``repro/kernels/hist``, which implements the same contract as a
+one-hot MXU matmul.
 
 ``axis_names`` turns this into the *distributed* histogram: rows are sharded
 across the named mesh axes and partial histograms are psum'd — exactly
@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.dispatch import resolve_impl
+from repro.kernels.hist.hist_kernel import histogram_pallas
+from repro.kernels.hist.ref import histogram_ref
 
 
 def build_histogram(codes, node_id, g, w, n_nodes: int, n_bins: int,
@@ -34,26 +36,11 @@ def build_histogram(codes, node_id, g, w, n_nodes: int, n_bins: int,
     already-compiled trainer the choice is baked in at trace time.
     """
     impl = resolve_impl(impl, env_var="REPRO_HIST_IMPL")
-    if impl != "xla":
-        from repro.kernels.hist.hist_kernel import histogram_pallas
+    if impl == "xla":
+        sums, cnt = histogram_ref(codes, node_id, g, w, n_nodes, n_bins)
+    else:
         sums, cnt = histogram_pallas(codes, node_id, g, w, n_nodes, n_bins,
                                      interpret=(impl == "pallas_interpret"))
-        for ax in axis_names:
-            sums = jax.lax.psum(sums, ax)
-            cnt = jax.lax.psum(cnt, ax)
-        return sums, cnt
-    n, p = codes.shape
-    seg_base = node_id.astype(jnp.int32) * n_bins
-
-    def per_feature(codes_j):
-        seg = seg_base + codes_j.astype(jnp.int32)
-        sums = jax.ops.segment_sum(g * w[:, None], seg,
-                                   num_segments=n_nodes * n_bins)
-        cnt = jax.ops.segment_sum(w, seg, num_segments=n_nodes * n_bins)
-        return sums.reshape(n_nodes, n_bins, -1), cnt.reshape(n_nodes, n_bins)
-
-    sums, cnt = jax.vmap(per_feature, in_axes=1, out_axes=1)(codes)
-    # sums: [n_nodes, p, n_bins, out]; cnt: [n_nodes, p, n_bins]
     for ax in axis_names:
         sums = jax.lax.psum(sums, ax)
         cnt = jax.lax.psum(cnt, ax)

@@ -9,6 +9,13 @@ that XGBoost's C++ inference performs pointer-chasing for).
 Grid: (row_blocks, trees); trees accumulate into the same output block.
 VMEM per step: [R, p] row tile + [R, max(H, p, L)] one-hot — with R=256,
 p<=640, depth 7 (H=127, L=128) comfortably under v5e VMEM.
+
+The heap arrays travel as ``[T, 1, H]`` so one tree's block ``(1, 1, H)``
+spans the full last two dims (Mosaic refuses a ``(1, H)`` block over
+``[T, H]``: its second-to-last dim is neither 8-aligned nor the whole
+axis). The select matmuls run at ``HIGHEST`` precision: a one-hot row times
+an f32 operand must return that operand exactly (thresholds, leaf values
+and feature ids above 256 are not bf16-representable).
 """
 from __future__ import annotations
 
@@ -24,6 +31,15 @@ def _onehot(idx, size):
     return (idx[:, None] == iota).astype(jnp.float32)
 
 
+def _select(onehot, table, table_axis: int):
+    """``onehot [R, K]`` contracted with ``table`` over its ``table_axis``
+    (of size K) -> [R, C]; exact for f32 tables (one nonzero term per row)."""
+    return jax.lax.dot_general(onehot, table,
+                               (((1,), (table_axis,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _predict_kernel(x_ref, feat_ref, thr_ref, leaf_ref, out_ref, *,
                     depth: int):
     t = pl.program_id(1)
@@ -33,22 +49,23 @@ def _predict_kernel(x_ref, feat_ref, thr_ref, leaf_ref, out_ref, *,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     x = x_ref[...]                       # [R, p]
-    feat = feat_ref[...][0]              # [H]
+    feat = feat_ref[0].astype(jnp.float32)                # [1, H]
     # +inf sentinels ("never go right") must be finite: 0 * inf = NaN in the
     # one-hot select matmul. 1e30 exceeds any scaled feature value.
-    thr = jnp.clip(thr_ref[...][0], -1e30, 1e30)
-    leaf = leaf_ref[...][0]              # [L, out]
-    n_heap = feat.shape[0]
+    thr = jnp.clip(thr_ref[0], -1e30, 1e30)               # [1, H]
+    heap_vals = jnp.concatenate([feat, thr], axis=0)      # [2, H]
+    leaf = leaf_ref[0]                   # [L, out]
+    n_heap = heap_vals.shape[1]
     p = x.shape[1]
     node = jnp.zeros((x.shape[0],), jnp.int32)
     for level in range(depth):
         heap = node + (2 ** level - 1)
         sel = _onehot(heap, n_heap)                       # [R, H]
-        f = jnp.round(sel @ feat.astype(jnp.float32)).astype(jnp.int32)
-        tv = sel @ thr                                    # [R]
+        ft = _select(sel, heap_vals, 1)                   # [R, 2]
+        f = jnp.round(ft[:, 0]).astype(jnp.int32)
         xv = jnp.sum(x * _onehot(f, p), axis=1)           # [R]
-        node = node * 2 + (xv > tv).astype(jnp.int32)
-    out_ref[...] += _onehot(node, leaf.shape[0]) @ leaf   # [R, out]
+        node = node * 2 + (xv > ft[:, 1]).astype(jnp.int32)
+    out_ref[...] += _select(_onehot(node, leaf.shape[0]), leaf, 0)
 
 
 def forest_predict_pallas(x, feat, thr_val, leaf, depth: int,
@@ -65,7 +82,7 @@ def forest_predict_pallas(x, feat, thr_val, leaf, depth: int,
     n, p = x.shape
     n_trees, n_heap = feat.shape
     n_leaves, out = leaf.shape[1], leaf.shape[2]
-    rows_block = min(rows_block, n)
+    rows_block = min(rows_block, pl.cdiv(n, 8) * 8)   # sublane-aligned
     n_pad = pl.cdiv(n, rows_block) * rows_block
     if n_pad != n:
         x = jnp.pad(x, ((0, n_pad - n), (0, 0)))
@@ -76,13 +93,13 @@ def forest_predict_pallas(x, feat, thr_val, leaf, depth: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((rows_block, p), lambda r, t: (r, 0)),
-            pl.BlockSpec((1, n_heap), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, n_heap), lambda r, t: (t, 0)),
+            pl.BlockSpec((1, 1, n_heap), lambda r, t: (t, 0, 0)),
+            pl.BlockSpec((1, 1, n_heap), lambda r, t: (t, 0, 0)),
             pl.BlockSpec((1, n_leaves, out), lambda r, t: (t, 0, 0)),
         ],
         out_specs=pl.BlockSpec((rows_block, out), lambda r, t: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, out), jnp.float32),
         interpret=interpret,
-    )(x.astype(jnp.float32), feat.astype(jnp.int32),
-      thr_val.astype(jnp.float32), leaf.astype(jnp.float32))
+    )(x.astype(jnp.float32), feat.astype(jnp.int32)[:, None],
+      thr_val.astype(jnp.float32)[:, None], leaf.astype(jnp.float32))
     return res if n_pad == n else res[:n]

@@ -39,6 +39,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _npz_batches(path: str, batch_rows: int):
     """.npy sources stream via a true memmap (only the yielded chunk is
@@ -143,6 +145,7 @@ def main(argv=None):
                          "registry as Prometheus text ('-' for stdout)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.data.store import ingest
 

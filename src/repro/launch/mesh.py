@@ -1,20 +1,37 @@
 """Production meshes. Defined as functions so importing never touches jax
-device state (the dry-run must set XLA_FLAGS before any initialisation)."""
+device state (the dry-run must set XLA_FLAGS before any initialisation).
+
+Every ``("data", "model")`` mesh in the repo is built by :func:`make_mesh`,
+which pins ``Auto`` axis types: the sharded solve constrains with
+``with_sharding_constraint`` and the shard_map trainer gathers with plain
+indexing, both of which need GSPMD-style (``Auto``) axes. ``jax.make_mesh``
+alone defaults to ``Explicit`` axes, under which both fail.
+"""
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int],
+              axes: Sequence[str] = ("data", "model")):
+    """``jax.make_mesh`` with ``Auto`` axis types (see module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (256 chips, one v5e pod) or 2x16x16 (512 chips, two pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 4, n_model: int = 2):
     """Small host-device mesh for tests (requires matching device count)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model))
 
 
 def auto_forest_mesh(model_axis_max: int = 8):
@@ -33,4 +50,4 @@ def auto_forest_mesh(model_axis_max: int = 8):
     while (model * 2 <= model_axis_max and (model * 2) ** 2 <= n
            and n % (model * 2) == 0):
         model *= 2
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model))

@@ -42,6 +42,8 @@ import time
 import urllib.error
 import urllib.request
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def swap_model(server: str, model: str, path: str, timeout: float = 60.0
                ) -> dict:
@@ -102,6 +104,7 @@ def main(argv=None):
                     help="write the process metrics registry as Prometheus "
                          "text ('-' for stdout)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if bool(args.server) != bool(args.model):
         raise SystemExit("--server and --model go together")
 

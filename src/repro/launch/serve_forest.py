@@ -49,6 +49,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import MetricsRegistry, Tracer
 from repro.serving import (AdmissionController, InflightScheduler,
                            ModelRegistry)
@@ -244,6 +245,7 @@ def main():
     ap.add_argument("--trace-jsonl", default=None, metavar="PATH",
                     help="after serving, dump the span ring as JSON lines")
     args = ap.parse_args()
+    enable_compile_cache()
 
     path = args.artifacts
     if args.demo or path is None:

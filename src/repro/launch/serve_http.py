@@ -70,6 +70,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import CONTENT_TYPE as _METRICS_CONTENT_TYPE
 from repro.obs import (MetricsRegistry, ProfileInProgress, Profiler,
                        ResourceMonitor, SlowLog, Tracer, render_prometheus)
@@ -475,6 +476,7 @@ def main(argv=None):
     ap.add_argument("--verbose", action="store_true",
                     help="log one line per HTTP request")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     specs = []
     for item in args.model:

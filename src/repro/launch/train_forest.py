@@ -39,20 +39,21 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def parse_mesh(spec: str):
     """``auto`` | ``none`` | ``DxM`` (e.g. ``4x2`` — data x model)."""
-    import jax
+    from repro.launch.mesh import auto_forest_mesh, make_mesh
 
     if spec == "none":
         return None
     if spec == "auto":
-        from repro.launch.mesh import auto_forest_mesh
         return auto_forest_mesh()
     dims = tuple(int(d) for d in spec.split("x"))
     if len(dims) != 2:
         raise ValueError(f"--mesh {spec!r}: expected 'auto', 'none' or DxM")
-    return jax.make_mesh(dims, ("data", "model"))
+    return make_mesh(dims)
 
 
 def _demo_data(n: int, p: int, n_y: int, seed: int):
@@ -113,6 +114,7 @@ def main(argv=None):
     ap.add_argument("--early-stop-rounds", type=int, default=0)
     ap.add_argument("--int8-codes", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     import jax
 

@@ -59,6 +59,7 @@ the PR-2 serial loop, and both paths build bit-identical batches.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import queue
@@ -555,6 +556,56 @@ def _run_grid_batches_pipelined(dispatch, collect, grid, bs: int, *,
 # single-device trainer
 # ---------------------------------------------------------------------------
 
+def ensemble_inputs(fcfg: ForestConfig, Xc, Wc, root, t, y_idx, eid):
+    """Noised inputs and codes of one (t, y) ensemble of the single-device
+    trainer; transient by design (built inside the jitted fit step).
+
+    Returns ``(codes, tgt, w, edges, codes_v, tgt_v, x_t, x_t_v)`` over the
+    class's ``n_max * duplicate_k`` duplicated rows.
+    """
+    K = fcfg.duplicate_k
+    x0d = jnp.repeat(Xc[y_idx], K, axis=0)               # [mK, p]
+    wd = jnp.repeat(Wc[y_idx], K, axis=0)
+    k_tr = jax.random.fold_in(root, eid * 2)
+    k_va = jax.random.fold_in(root, eid * 2 + 1)
+    _, xt, tgt = itp.sample_bridge(k_tr, x0d, fcfg.method, t, fcfg.sigma)
+    edges = weighted_edges(xt, wd, fcfg.n_bins)
+    codes = transform(xt, edges)
+    _, xtv, tgtv = itp.sample_bridge(k_va, x0d, fcfg.method, t, fcfg.sigma)
+    codes_v = transform(xtv, edges)
+    if fcfg.int8_codes:   # QuantileDMatrix-style narrow storage
+        codes = pack_codes(codes, fcfg.n_bins)
+        codes_v = pack_codes(codes_v, fcfg.n_bins)
+    return codes, tgt, wd, edges, codes_v, tgtv, xt, xtv
+
+
+@functools.lru_cache(maxsize=16)
+def single_fit_program(fcfg: ForestConfig, warm: bool = False):
+    """The jitted single-device fit step over a batch of ensembles.
+
+    ``fn(Xc [n_y, n_max, p], Wc [n_y, n_max], root key, t [b], y [b],
+    eid [b]) -> BoostResult`` stacked over the ``b`` ensembles of the batch
+    (``warm=True`` adds the five base-model slices ``feat [b, n_sub, R, H],
+    thr_val, leaf, val_curve, best_round``). The padded class blocks are
+    arguments, not closure constants, so the program compiles once per
+    (config, shapes) and can be lowered from shapes alone — the memory
+    check that sizes ``ensembles_per_batch`` for a device does exactly that.
+    """
+    def fit_one(Xc, Wc, root, t, y_idx, eid, *warm_slice):
+        """Train (or, with a base-model slice, continue) one ensemble."""
+        codes, tgt, wd, edges, codes_v, tgtv, xt, xtv = \
+            ensemble_inputs(fcfg, Xc, Wc, root, t, y_idx, eid)
+        if not warm_slice:
+            return fit_ensemble(codes, tgt, wd, edges_with_sentinel(edges),
+                                codes_v, tgtv, wd, fcfg)
+        return fit_ensemble(codes, tgt, wd, edges_with_sentinel(edges),
+                            codes_v, tgtv, wd, fcfg, warm=warm_slice,
+                            x_raw=xt, val_raw=xtv)
+
+    in_axes = (None, None, None, 0, 0, 0) + ((0,) * 5 if warm else ())
+    return jax.jit(jax.vmap(fit_one, in_axes=in_axes))
+
+
 def fit_artifacts(X, y=None, fcfg: ForestConfig = ForestConfig(), *,
                   seed: int = 0, checkpoint_dir: Optional[str] = None,
                   resume: bool = False, ensembles_per_batch: int = 0,
@@ -614,7 +665,8 @@ def fit_artifacts(X, y=None, fcfg: ForestConfig = ForestConfig(), *,
         # out-of-core route: the sharded trainer streams per-device row
         # slices from the store's shards; the single-device route would
         # densify the whole dataset into padded class blocks
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1))
     # validate on every path: a malformed pipeline knob should fail loudly
     # on a single-device box too, not first on the production mesh
     if pipeline == "auto":
@@ -647,49 +699,9 @@ def fit_artifacts(X, y=None, fcfg: ForestConfig = ForestConfig(), *,
     ts = np.asarray(itp.timesteps(fcfg.method, fcfg.n_t, fcfg.eps_diff,
                                   fcfg.t_schedule))
     root = jax.random.PRNGKey(seed)
-
-    K = fcfg.duplicate_k
-
-    def ensemble_inputs(t, y_idx, eid):
-        """Noised inputs/codes of the (t, y) ensemble; transient by design."""
-        x0 = Xc_d[y_idx]
-        w = Wc_d[y_idx]
-        x0d = jnp.repeat(x0, K, axis=0)                  # [mK, p]
-        wd = jnp.repeat(w, K, axis=0)
-        k_tr = jax.random.fold_in(root, eid * 2)
-        k_va = jax.random.fold_in(root, eid * 2 + 1)
-        _, xt, tgt = itp.sample_bridge(k_tr, x0d, fcfg.method, t, fcfg.sigma)
-        edges = weighted_edges(xt, wd, fcfg.n_bins)
-        codes = transform(xt, edges)
-        _, xtv, tgtv = itp.sample_bridge(k_va, x0d, fcfg.method, t,
-                                         fcfg.sigma)
-        codes_v = transform(xtv, edges)
-        if fcfg.int8_codes:   # QuantileDMatrix-style narrow storage
-            codes = pack_codes(codes, fcfg.n_bins)
-            codes_v = pack_codes(codes_v, fcfg.n_bins)
-        return codes, tgt, wd, edges, codes_v, tgtv, xt, xtv
-
-    def fit_one(t, y_idx, eid):
-        """Train the (t, y) ensemble; everything transient lives here."""
-        codes, tgt, wd, edges, codes_v, tgtv, _, _ = \
-            ensemble_inputs(t, y_idx, eid)
-        return fit_ensemble(codes, tgt, wd, edges_with_sentinel(edges),
-                            codes_v, tgtv, wd, fcfg)
-
-    def fit_one_warm(t, y_idx, eid, wf, wt, wl, wvc, wbr):
-        """Continue the (t, y) ensemble from its base-model slice."""
-        codes, tgt, wd, edges, codes_v, tgtv, xt, xtv = \
-            ensemble_inputs(t, y_idx, eid)
-        return fit_ensemble(codes, tgt, wd, edges_with_sentinel(edges),
-                            codes_v, tgtv, wd, fcfg,
-                            warm=(wf, wt, wl, wvc, wbr), x_raw=xt,
-                            val_raw=xtv)
-
-    if warm_start is None:
-        fit_batch = jax.jit(jax.vmap(fit_one, in_axes=(0, 0, 0)))
-    else:
+    fit_batch = single_fit_program(fcfg, warm=warm_start is not None)
+    if warm_start is not None:
         Wfeat, Wthr, Wleaf, Wvc, Wbr = _warm_host_arrays(warm_start)
-        fit_batch = jax.jit(jax.vmap(fit_one_warm, in_axes=(0,) * 8))
 
     grid = [(ti, yi) for ti in range(fcfg.n_t) for yi in range(n_y)]
     bs = ensembles_per_batch or max(1, min(len(grid), 8))
@@ -699,11 +711,11 @@ def fit_artifacts(X, y=None, fcfg: ForestConfig = ForestConfig(), *,
         y_arr = jnp.asarray([yi for _, yi in chunk], jnp.int32)
         e_arr = jnp.asarray([ti * n_y + yi for ti, yi in chunk], jnp.int32)
         if warm_start is None:
-            res = fit_batch(t_arr, y_arr, e_arr)
+            res = fit_batch(Xc_d, Wc_d, root, t_arr, y_arr, e_arr)
         else:
             tis = [ti for ti, _ in chunk]
             yis = [yi for _, yi in chunk]
-            res = fit_batch(t_arr, y_arr, e_arr,
+            res = fit_batch(Xc_d, Wc_d, root, t_arr, y_arr, e_arr,
                             jnp.asarray(Wfeat[tis, yis]),
                             jnp.asarray(Wthr[tis, yis]),
                             jnp.asarray(Wleaf[tis, yis]),

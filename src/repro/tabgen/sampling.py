@@ -166,6 +166,12 @@ class SampleHandle:
         self.trace_ids = tuple(trace_ids)
         return self
 
+    @property
+    def sharding(self):
+        """Placement of the in-flight ``[n_y, m, p]`` device result — under
+        ``mesh=`` it spans every device of the mesh."""
+        return self._x_dev.sharding
+
     def result(self) -> Tuple[np.ndarray, np.ndarray]:
         x_all = np.asarray(self._x_dev)             # blocks: [n_y, m, p]
         X = np.concatenate([x_all[yi, :c]
@@ -191,6 +197,12 @@ def sample_async(artifacts: ForestArtifacts, n: int, *,
     _, spec = _resolve_sampler(fcfg, sampler)
     impl = resolve_impl(impl, fcfg.predict_impl, env_var=_PREDICT_ENV)
     mesh = resolve_mesh(mesh)
+    if mesh is not None and impl == "pallas":
+        # GSPMD cannot partition a Mosaic kernel; it needs a shard_map
+        # route, which the solve does not have yet
+        raise ValueError("impl='pallas' has no mesh route: sample with "
+                         "mesh=None for the kernel, or impl='xla' under "
+                         "a mesh")
     rng = np.random.default_rng(seed)
     label_idx = sample_labels(artifacts.counts, n, rng, fcfg.label_sampler)
     n_y = artifacts.n_y

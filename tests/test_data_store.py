@@ -23,6 +23,7 @@ from repro.data.tabular import (correlated_gaussian_batches,
                                 two_moons_batches)
 from repro.forest.binning import fit_bins, fit_bins_streaming, pack_codes, \
     transform
+from repro.launch.mesh import make_mesh
 from repro.tabgen import fit_artifacts
 from repro.tabgen.fitting import class_stats_streaming, weighted_edges
 
@@ -40,7 +41,7 @@ def _equal(a, b):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1))
 
 
 @pytest.fixture(scope="module")

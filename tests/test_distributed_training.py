@@ -79,6 +79,7 @@ import numpy as np
 
 from repro.config import ForestConfig
 from repro.eval import metrics as M
+from repro.launch.mesh import make_mesh
 from repro.tabgen import TabularGenerator, fit_artifacts, sample
 
 assert len(jax.devices()) == 8
@@ -102,8 +103,8 @@ def class_err(art):
     return G, max(errs)
 
 art_single = fit_artifacts(X, y, fcfg, seed=0)
-meshes = {"1x1": jax.make_mesh((1, 1), ("data", "model")),
-          "4x2": jax.make_mesh((4, 2), ("data", "model"))}
+meshes = {"1x1": make_mesh((1, 1)),
+          "4x2": make_mesh((4, 2))}
 G0, err0 = class_err(art_single)
 report = {"single": err0}
 for name, mesh in meshes.items():
@@ -142,7 +143,7 @@ report["resume_equal"] = bool(
 
 # elastic resume: a different mesh shape with no pinned batch size inherits
 # the manifest's ensembles_per_batch instead of refusing on the fingerprint
-mesh24 = jax.make_mesh((2, 4), ("data", "model"))
+mesh24 = make_mesh((2, 4))
 art_el = fit_artifacts(X, y, fcfg, seed=0, mesh=mesh24,
                        checkpoint_dir=ck_full, resume=True)
 report["elastic_equal"] = bool(

@@ -13,14 +13,14 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
 
 from repro.config import ForestConfig
 from repro.forest.distributed import make_distributed_fit
 from repro.forest.packed import PackedForest, predict_forest
+from repro.launch.mesh import make_mesh
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2))
 
 rng = np.random.default_rng(0)
 n, p = 512, 4
@@ -81,6 +81,7 @@ import numpy as np
 
 from repro.config import ForestConfig
 from repro.data.tabular import two_moons
+from repro.launch.mesh import make_mesh
 from repro.launch.serve_forest import ForestServer
 from repro.tabgen import fit_artifacts, sample
 
@@ -88,7 +89,7 @@ X, y = two_moons(300, seed=0)
 fcfg = ForestConfig(n_t=5, duplicate_k=6, n_trees=8, max_depth=3, n_bins=16,
                     reg_lambda=1.0)
 art = fit_artifacts(X, y, fcfg, seed=0)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2))
 
 # sharded == single-device, bit-for-bit under a fixed seed (noise is drawn
 # per (class, row) counter, so the partitioning cannot change values);

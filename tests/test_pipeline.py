@@ -11,11 +11,11 @@ import json
 import threading
 import time
 
-import jax
 import numpy as np
 import pytest
 
 from repro.config import ForestConfig
+from repro.launch.mesh import make_mesh
 from repro.tabgen import PipelineConfig, fit_artifacts
 from repro.tabgen import fitting
 from repro.train import checkpoint as ckpt
@@ -36,7 +36,7 @@ def data():
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1))
 
 
 def _equal(a, b):

@@ -147,3 +147,11 @@ def test_hist_env_resolved_per_call(monkeypatch):
     monkeypatch.setenv("REPRO_HIST_IMPL", "bogus")
     with pytest.raises(ValueError, match="bogus"):
         build_histogram(codes, nid, g, w, 2, 8)
+
+
+def test_compiled_kernel_refuses_a_mesh(flow_so):
+    """A Mosaic kernel cannot be partitioned by GSPMD: ``sample`` says so
+    up front instead of failing inside the TPU lowering."""
+    from repro.launch.mesh import make_mesh
+    with pytest.raises(ValueError, match="no mesh route"):
+        sample(flow_so, 16, seed=0, mesh=make_mesh((1, 1)), impl="pallas")

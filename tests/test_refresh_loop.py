@@ -12,11 +12,11 @@ import json
 import threading
 import time
 
-import jax
 import numpy as np
 import pytest
 
 from repro.config import ForestConfig
+from repro.launch.mesh import make_mesh
 from repro.data.store import DatasetStore, ingest
 from repro.tabgen import (TabularGenerator, extend_artifacts, fit_artifacts)
 from repro.tabgen.fitting import class_stats_streaming
@@ -37,7 +37,7 @@ def _assert_same(a, b):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1))
 
 
 @pytest.fixture(scope="module")

@@ -1,5 +1,6 @@
 """Serving loop + elastic checkpoint re-mesh + dry-run artifact integrity."""
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -35,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.sharding import rules
 from repro.train import checkpoint as ckpt
@@ -46,7 +48,7 @@ d = tempfile.mkdtemp()
 ckpt.save(d, 1, params)
 
 # restore onto a DIFFERENT mesh shape (elastic re-scale: 4x2 -> 2x4)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4))
 restored, step = ckpt.restore(d, params)
 specs = rules.param_specs(params, cfg, ("data",), "model", 2, 4)
 sharded = ckpt.reshard(restored, mesh, specs)
@@ -70,7 +72,8 @@ def test_elastic_remesh_restore():
     out = subprocess.run([sys.executable, "-c", _ELASTIC],
                          capture_output=True, text=True, timeout=600,
                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                              "HOME": "/root"})
+                              "HOME": os.environ.get("HOME", "."),
+                              "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-3000:]
     assert json.loads(out.stdout.strip().splitlines()[-1])["ok"]
 

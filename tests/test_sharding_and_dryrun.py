@@ -66,6 +66,7 @@ import jax.numpy as jnp
 
 from repro.config import SHAPES_BY_NAME, TrainConfig, ShapeConfig
 from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.sharding import rules
 from repro.train.optim import adamw_update
@@ -73,7 +74,7 @@ from repro.train.optim import adamw_update
 # reduced config, small shape, 4x2 mesh — full dry-run code path
 cfg = get_arch("smollm-135m", reduced=True)
 shape = ShapeConfig("mini_train", 64, 8, "train")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2))
 dp, tp = ("data",), "model"
 specs = lm.input_specs(cfg, shape, jnp.float32)
 params_shape = jax.eval_shape(
