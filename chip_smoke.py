@@ -382,7 +382,7 @@ def phase_sharded(art: ForestArtifacts, mesh, *, n: int, seed: int,
 
 
 # ---------------------------------------------------------------------------
-# driver
+# runs
 # ---------------------------------------------------------------------------
 
 def run_one_chip(seed: int) -> None:
