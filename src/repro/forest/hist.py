@@ -34,13 +34,19 @@ def build_histogram(codes, node_id, g, w, n_nodes: int, n_bins: int,
     REPRO_HIST_IMPL=pallas) is resolved per call — setting the env var after
     import works, unlike the old module-level snapshot. Inside an
     already-compiled trainer the choice is baked in at trace time.
+
+    The build runs under ``jax.named_scope("hist")``, so a profiler trace
+    finds its device ops whichever impl runs; the cross-device sums over
+    ``axis_names`` stay outside the scope.
     """
     impl = resolve_impl(impl, env_var="REPRO_HIST_IMPL")
-    if impl == "xla":
-        sums, cnt = histogram_ref(codes, node_id, g, w, n_nodes, n_bins)
-    else:
-        sums, cnt = histogram_pallas(codes, node_id, g, w, n_nodes, n_bins,
-                                     interpret=(impl == "pallas_interpret"))
+    with jax.named_scope("hist"):
+        if impl == "xla":
+            sums, cnt = histogram_ref(codes, node_id, g, w, n_nodes, n_bins)
+        else:
+            sums, cnt = histogram_pallas(
+                codes, node_id, g, w, n_nodes, n_bins,
+                interpret=(impl == "pallas_interpret"))
     for ax in axis_names:
         sums = jax.lax.psum(sums, ax)
         cnt = jax.lax.psum(cnt, ax)

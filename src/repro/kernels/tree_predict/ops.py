@@ -7,6 +7,12 @@ resolved per call — explicit argument first, then the
 ``REPRO_TREE_PREDICT_IMPL`` environment variable, then ``xla`` — and passed
 to the jitted core as a static argument, so each impl compiles its own
 program and switching at runtime just selects a different cache entry.
+
+The traversal runs under ``jax.named_scope("tree_predict")`` inside the
+jitted core, so the device ops of either impl carry ``tree_predict`` in
+their op name, alone or inlined in a larger program (a scope opened
+around a call of a jitted function does not reach a top-level call).
+A profiler trace finds the traversal by that name whatever implements it.
 """
 from __future__ import annotations
 
@@ -24,10 +30,11 @@ ENV_VAR = "REPRO_TREE_PREDICT_IMPL"
 
 @functools.partial(jax.jit, static_argnames=("depth", "impl"))
 def _forest_predict(x, feat, thr_val, leaf, depth: int, impl: str):
-    if impl == "xla":
-        return forest_predict_ref(x, feat, thr_val, leaf, depth)
-    return forest_predict_pallas(x, feat, thr_val, leaf, depth,
-                                 interpret=(impl == "pallas_interpret"))
+    with jax.named_scope("tree_predict"):
+        if impl == "xla":
+            return forest_predict_ref(x, feat, thr_val, leaf, depth)
+        return forest_predict_pallas(x, feat, thr_val, leaf, depth,
+                                     interpret=(impl == "pallas_interpret"))
 
 
 def forest_predict(x, feat, thr_val, leaf, depth: int,

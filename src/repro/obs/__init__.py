@@ -14,8 +14,9 @@ visibility.  Three stdlib-only pieces:
 * :mod:`repro.obs.tracing` — ring-buffered :class:`Tracer` spans
   threaded through the serving hot path, the fit pipeline, and
   ``DatasetStore.ingest``; queue-wait vs device-time comes from span
-  durations, with optional JSONL export and ``jax.profiler``
-  trace-annotation passthrough (``REPRO_OBS_JAX_TRACE=1``).  Spans carry
+  durations, with optional JSONL export.  Scoped spans always appear
+  in a ``jax.profiler`` capture (``POST /debug/profile`` shows them),
+  on the device trace's clock.  Spans carry
   trace context (``trace_id`` / ``links``) so ``Tracer.trace(rid)``
   reconstructs a per-request timeline; :class:`SlowLog` is the
   append-only sink for over-threshold request timelines.

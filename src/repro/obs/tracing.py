@@ -24,19 +24,19 @@ can never skew from the span (the scheduler's one-reading contract).
 ``tracer.spans(name=...)`` queries completed spans (oldest first);
 ``tracer.export_jsonl(path)`` dumps them for offline tooling (truncating
 by default; ``append=True`` accumulates across dumps — the
-:class:`SlowLog` below is always append).  Setting
-``REPRO_OBS_JAX_TRACE=1`` (or ``Tracer(jax_annotations=True)``) wraps
-scoped spans in ``jax.profiler.TraceAnnotation`` so they show up on the
-device timeline in a jax profiler capture — resolved lazily per span, so
-this module stays importable without jax and never snapshots the env at
-import time.
+:class:`SlowLog` below is always append).  Every scoped span also enters
+a ``jax.profiler.TraceAnnotation`` of its name whenever jax is
+importable, so scoped spans always appear, on the device trace's clock,
+in a jax profiler capture (``POST /debug/profile`` shows them).  With no
+profiler session active an annotation is one flag check.  jax is looked
+up per span, so this module stays importable without it.  Split-form
+spans (``start``/``end``) cross threads and carry no annotation.
 """
 from __future__ import annotations
 
 import contextlib
 import itertools
 import json
-import os
 import threading
 import time
 from collections import deque
@@ -122,12 +122,10 @@ class Tracer:
     batch).
     """
 
-    def __init__(self, capacity: int = 2048,
-                 jax_annotations: Optional[bool] = None):
+    def __init__(self, capacity: int = 2048):
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         self.capacity = capacity
-        self._jax_annotations = jax_annotations
         self._lock = threading.Lock()
         # eviction is manual (not deque(maxlen=...)): the trace index below
         # must drop exactly the spans the ring drops, or an evicted span
@@ -171,14 +169,10 @@ class Tracer:
                         if not bucket:
                             del self._by_trace[tid]
 
-    def _jax_annotation(self, name: str):
-        """A ``jax.profiler.TraceAnnotation`` for scoped spans, or a
-        null context.  The env knob is read per call, not at import."""
-        on = self._jax_annotations
-        if on is None:
-            on = os.environ.get("REPRO_OBS_JAX_TRACE", "") not in ("", "0")
-        if not on:
-            return contextlib.nullcontext()
+    @staticmethod
+    def _jax_annotation(name: str):
+        """A ``jax.profiler.TraceAnnotation`` of ``name``, or a null
+        context where jax cannot be imported."""
         try:
             from jax.profiler import TraceAnnotation
         except Exception:

@@ -20,6 +20,14 @@ per (class, row) counter, so the sharded solve is value-identical to the
 single-device one). ``impl`` picks the tree-traversal backend and is
 resolved per call (argument > ``ForestConfig.predict_impl`` >
 ``REPRO_TREE_PREDICT_IMPL`` > ``xla``).
+
+Each call records scoped spans on :func:`repro.obs.default_tracer`, which
+also reach a jax profiler capture: ``sample.prepare`` and
+``sample.dispatch`` in :func:`sample_async`, ``sample.wait``,
+``sample.fetch`` and ``sample.finish`` in :meth:`SampleHandle.result`,
+each with ``rows`` (asked for), ``bucket`` (rows solved per class) and
+``classes``. In the device program the named scopes ``sample.noise`` and
+``sample.unscale`` (and ``tree_predict`` in the traversal) name the ops.
 """
 from __future__ import annotations
 
@@ -35,6 +43,7 @@ from repro.core import interpolants as itp
 from repro.forest.packed import PackedForest
 from repro.kernels.dispatch import resolve_impl
 from repro.kernels.tree_predict.ops import ENV_VAR as _PREDICT_ENV
+from repro.obs import default_tracer
 from repro.tabgen.artifacts import ForestArtifacts, solve_axes, unscale
 from repro.tabgen.samplers import default_sampler, get_sampler
 
@@ -109,14 +118,17 @@ def _solve_all_classes(feat, thr_val, leaf, keys, mins, maxs, ts, *,
         # counter-based per-row noise: row i draws the same x1 whatever the
         # bucket m, so deterministic samplers are padding-invariant (a
         # request served at bucket 256 equals the same request at 1024)
-        row_keys = jax.vmap(jax.random.fold_in, (None, 0))(k_x1, jnp.arange(m))
-        x1 = jax.vmap(
-            lambda k: jax.random.normal(k, (mn.shape[0],), jnp.float32)
-        )(row_keys)
+        with jax.named_scope("sample.noise"):
+            row_keys = jax.vmap(jax.random.fold_in, (None, 0))(
+                k_x1, jnp.arange(m))
+            x1 = jax.vmap(
+                lambda k: jax.random.normal(k, (mn.shape[0],), jnp.float32)
+            )(row_keys)
         forests = PackedForest(feat_c, thr_c, leaf_c, multi_output)
         x0 = solver_fn(x1, forests, depth=depth, n_t=n_t, ts=ts,
                        key=k_solve, eps=eps, impl=impl)
-        return unscale(x0, mn, mx)
+        with jax.named_scope("sample.unscale"):
+            return unscale(x0, mn, mx)
 
     out = jax.vmap(one_class, in_axes=(1, 1, 1, 0, 0, 0))(
         feat, thr_val, leaf, keys, mins, maxs)
@@ -153,6 +165,9 @@ class SampleHandle:
         self._per_class = per_class
         self._classes = classes
         self._rng = rng
+        self._span_attrs = {"rows": int(np.sum(per_class)),
+                            "bucket": int(x_dev.shape[1]),
+                            "classes": len(per_class)}
         # trace context, stamped by the serving scheduler via tag(): which
         # coalesced batch this dispatch is, and which request traces ride it
         self.batch_id: Optional[int] = None
@@ -173,12 +188,17 @@ class SampleHandle:
         return self._x_dev.sharding
 
     def result(self) -> Tuple[np.ndarray, np.ndarray]:
-        x_all = np.asarray(self._x_dev)             # blocks: [n_y, m, p]
-        X = np.concatenate([x_all[yi, :c]
-                            for yi, c in enumerate(self._per_class)])
-        y = np.repeat(self._classes, self._per_class)
-        perm = self._rng.permutation(len(X))
-        return X[perm], y[perm]
+        tracer, attrs = default_tracer(), self._span_attrs
+        with tracer.span("sample.wait", **attrs):
+            self._x_dev.block_until_ready()
+        with tracer.span("sample.fetch", **attrs):
+            x_all = np.asarray(self._x_dev)         # [n_y, m, p]
+        with tracer.span("sample.finish", **attrs):
+            X = np.concatenate([x_all[yi, :c]
+                                for yi, c in enumerate(self._per_class)])
+            y = np.repeat(self._classes, self._per_class)
+            perm = self._rng.permutation(len(X))
+            return X[perm], y[perm]
 
 
 def sample_async(artifacts: ForestArtifacts, n: int, *,
@@ -194,33 +214,38 @@ def sample_async(artifacts: ForestArtifacts, n: int, *,
     paths share one jit cache and one output distribution by construction.
     """
     fcfg = artifacts.config
-    _, spec = _resolve_sampler(fcfg, sampler)
-    impl = resolve_impl(impl, fcfg.predict_impl, env_var=_PREDICT_ENV)
-    mesh = resolve_mesh(mesh)
-    if mesh is not None and impl == "pallas":
-        # GSPMD cannot partition a Mosaic kernel; it needs a shard_map
-        # route, which the solve does not have yet
-        raise ValueError("impl='pallas' has no mesh route: sample with "
-                         "mesh=None for the kernel, or impl='xla' under "
-                         "a mesh")
-    rng = np.random.default_rng(seed)
-    label_idx = sample_labels(artifacts.counts, n, rng, fcfg.label_sampler)
-    n_y = artifacts.n_y
-    per_class = np.bincount(label_idx, minlength=n_y)
-    m = int(per_class.max())
-    if pad_to is not None:
-        if pad_to < m:
-            raise ValueError(f"pad_to={pad_to} < largest class batch {m}")
-        m = int(pad_to)
-    ts = jnp.asarray(itp.timesteps(fcfg.method, fcfg.n_t, fcfg.eps_diff,
-                                   fcfg.t_schedule))
-    keys = jax.random.split(jax.random.PRNGKey(seed + 7), n_y)
-    x_all = _solve_all_classes(
-        artifacts.feat, artifacts.thr_val, artifacts.leaf, keys,
-        artifacts.mins, artifacts.maxs, ts,
-        solver_fn=spec.fn, m=m, depth=fcfg.max_depth, n_t=fcfg.n_t,
-        multi_output=fcfg.multi_output, eps=fcfg.eps_diff, impl=impl,
-        mesh=mesh)
+    tracer = default_tracer()
+    with tracer.span("sample.prepare", rows=n) as sp:
+        _, spec = _resolve_sampler(fcfg, sampler)
+        impl = resolve_impl(impl, fcfg.predict_impl, env_var=_PREDICT_ENV)
+        mesh = resolve_mesh(mesh)
+        if mesh is not None and impl == "pallas":
+            # GSPMD cannot partition a Mosaic kernel; it needs a shard_map
+            # route, which the solve does not have yet
+            raise ValueError("impl='pallas' has no mesh route: sample with "
+                             "mesh=None for the kernel, or impl='xla' under "
+                             "a mesh")
+        rng = np.random.default_rng(seed)
+        label_idx = sample_labels(artifacts.counts, n, rng,
+                                  fcfg.label_sampler)
+        n_y = artifacts.n_y
+        per_class = np.bincount(label_idx, minlength=n_y)
+        m = int(per_class.max())
+        if pad_to is not None:
+            if pad_to < m:
+                raise ValueError(f"pad_to={pad_to} < largest class batch {m}")
+            m = int(pad_to)
+        ts = jnp.asarray(itp.timesteps(fcfg.method, fcfg.n_t, fcfg.eps_diff,
+                                       fcfg.t_schedule))
+        keys = jax.random.split(jax.random.PRNGKey(seed + 7), n_y)
+        sp.attrs.update(bucket=m, classes=n_y)
+    with tracer.span("sample.dispatch", **sp.attrs):
+        x_all = _solve_all_classes(
+            artifacts.feat, artifacts.thr_val, artifacts.leaf, keys,
+            artifacts.mins, artifacts.maxs, ts,
+            solver_fn=spec.fn, m=m, depth=fcfg.max_depth, n_t=fcfg.n_t,
+            multi_output=fcfg.multi_output, eps=fcfg.eps_diff, impl=impl,
+            mesh=mesh)
     return SampleHandle(x_all, per_class, np.asarray(artifacts.classes), rng)
 
 

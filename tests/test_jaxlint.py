@@ -206,13 +206,13 @@ def test_jx002_function_scope_read_is_clean():
 
 def test_jx002_class_method_read_is_clean():
     # a per-call env read inside a method runs at call time, not import
-    # time (the Tracer._jax_annotation shape) — PR-8 false-positive fix
+    # time — PR-8 false-positive fix
     assert rules_hit("""
         import os
 
-        class Tracer:
-            def annotation(self):
-                return os.environ.get("REPRO_OBS_JAX_TRACE", "")
+        class Predictor:
+            def impl(self):
+                return os.environ.get("REPRO_TREE_PREDICT_IMPL", "xla")
     """) == []
 
 
