@@ -154,6 +154,8 @@ class ForestConfig:
     int8_codes: bool = False    # store bin codes at int8 (4x HBM reduction)
     predict_impl: Optional[str] = None  # tree-predict backend for generation:
                                  # "xla" | "pallas" | "pallas_interpret";
-                                 # None defers to REPRO_TREE_PREDICT_IMPL
+                                 # None defers to REPRO_TREE_PREDICT_IMPL,
+                                 # then to the platform: the Pallas kernel
+                                 # on a TPU without a mesh, else xla
                                  # (resolved per sample/impute call)
     seed: int = 0

@@ -5,10 +5,10 @@ the Pallas ``tree_predict`` kernel consumes; ``predict_forest`` here routes
 every traversal through :func:`repro.kernels.tree_predict.ops.forest_predict`
 — one dispatch point, switchable between the XLA reference scan and the
 Pallas kernel per call (``impl=`` | ``ForestConfig.predict_impl`` |
-``REPRO_TREE_PREDICT_IMPL``) — so samplers, imputation, and serving all
-inherit the kernel without their own plumbing. One packed forest represents
-one (timestep, class) ensemble; the generator stacks them further to
-[n_t, ...] for the ODE/SDE solve.
+``REPRO_TREE_PREDICT_IMPL`` | the platform's default) — so samplers,
+imputation, and serving all inherit the kernel without their own
+plumbing. One packed forest represents one (timestep, class) ensemble;
+the generator stacks them further to [n_t, ...] for the ODE/SDE solve.
 """
 from __future__ import annotations
 

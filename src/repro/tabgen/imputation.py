@@ -25,6 +25,7 @@ from repro.forest.packed import PackedForest, predict_forest
 from repro.kernels.dispatch import resolve_impl
 from repro.kernels.tree_predict.ops import ENV_VAR as _PREDICT_ENV
 from repro.tabgen.artifacts import ForestArtifacts, rescale, unscale
+from repro.tabgen.sampling import predict_default
 
 
 def impute(artifacts: ForestArtifacts, X_missing, y=None, *, seed: int = 0,
@@ -32,11 +33,13 @@ def impute(artifacts: ForestArtifacts, X_missing, y=None, *, seed: int = 0,
     """Fill NaNs in ``X_missing``; observed cells are returned untouched.
 
     ``impl`` selects the tree-predict backend for every solver step of the
-    clamped solve (argument > ``ForestConfig.predict_impl`` > env > xla) —
-    the imputation loop inherits the kernel exactly like the samplers do.
+    clamped solve (argument > ``ForestConfig.predict_impl`` > env > the
+    platform's default) — the imputation loop inherits the kernel exactly
+    like the samplers do.
     """
     fcfg = artifacts.config
-    impl = resolve_impl(impl, fcfg.predict_impl, env_var=_PREDICT_ENV)
+    impl = resolve_impl(impl, fcfg.predict_impl, env_var=_PREDICT_ENV,
+                        default=predict_default(artifacts))
     X_missing = np.asarray(X_missing, np.float32)
     n, p = X_missing.shape
     if y is None:

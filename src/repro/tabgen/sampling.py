@@ -19,7 +19,8 @@ class-vmapped axis over the ``model`` mesh axis, rows over the data axes
 per (class, row) counter, so the sharded solve is value-identical to the
 single-device one). ``impl`` picks the tree-traversal backend and is
 resolved per call (argument > ``ForestConfig.predict_impl`` >
-``REPRO_TREE_PREDICT_IMPL`` > ``xla``).
+``REPRO_TREE_PREDICT_IMPL`` > :func:`~repro.kernels.tree_predict.ops.default_impl`:
+the Pallas kernel on a TPU without a mesh, XLA elsewhere).
 
 Each call records scoped spans on :func:`repro.obs.default_tracer`, which
 also reach a jax profiler capture: ``sample.prepare`` and
@@ -43,6 +44,7 @@ from repro.core import interpolants as itp
 from repro.forest.packed import PackedForest
 from repro.kernels.dispatch import resolve_impl
 from repro.kernels.tree_predict.ops import ENV_VAR as _PREDICT_ENV
+from repro.kernels.tree_predict.ops import default_impl
 from repro.obs import default_tracer
 from repro.tabgen.artifacts import ForestArtifacts, solve_axes, unscale
 from repro.tabgen.samplers import default_sampler, get_sampler
@@ -137,6 +139,13 @@ def _solve_all_classes(feat, thr_val, leaf, keys, mins, maxs, ts, *,
     return out
 
 
+def predict_default(artifacts: ForestArtifacts, mesh=None) -> str:
+    """:func:`default_impl` for these artifacts on the default backend."""
+    return default_impl(jax.default_backend(), artifacts.p,
+                        artifacts.leaf.shape[-1], mesh,
+                        artifacts.config.max_depth)
+
+
 def _resolve_sampler(fcfg, sampler: Optional[str]):
     """Name -> spec, validated against the artifacts' interpolant family."""
     name = sampler or default_sampler(fcfg.method, fcfg.diff_sampler)
@@ -217,8 +226,9 @@ def sample_async(artifacts: ForestArtifacts, n: int, *,
     tracer = default_tracer()
     with tracer.span("sample.prepare", rows=n) as sp:
         _, spec = _resolve_sampler(fcfg, sampler)
-        impl = resolve_impl(impl, fcfg.predict_impl, env_var=_PREDICT_ENV)
         mesh = resolve_mesh(mesh)
+        impl = resolve_impl(impl, fcfg.predict_impl, env_var=_PREDICT_ENV,
+                            default=predict_default(artifacts, mesh))
         if mesh is not None and impl == "pallas":
             # GSPMD cannot partition a Mosaic kernel; it needs a shard_map
             # route, which the solve does not have yet

@@ -9,10 +9,14 @@ switch resolved at call time (argument > ``ForestConfig.predict_impl`` >
   forests, odd row counts) and end-to-end through the euler/heun/ddim
   solvers and the imputation loop;
 * per-call env resolution (the old module-level snapshot ignored changes
-  made after import) for both the tree-predict and the hist switch.
+  made after import) for both the tree-predict and the hist switch;
+* the default when nothing is asked for (``default_impl``): the kernel on
+  a TPU without a mesh where its VMEM working set fits, XLA elsewhere, at
+  every site that resolves the impl.
 """
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -21,7 +25,10 @@ from repro.config import ForestConfig
 from repro.data.tabular import two_moons
 from repro.forest.hist import build_histogram
 from repro.forest.packed import PackedForest, predict_forest
-from repro.tabgen import fit_artifacts, impute, sample
+from repro.kernels.tree_predict import ops
+from repro.kernels.tree_predict.ops import default_impl
+from repro.launch.mesh import make_mesh
+from repro.tabgen import fit_artifacts, impute, imputation, sample, sampling
 
 
 @pytest.fixture(scope="module")
@@ -155,3 +162,103 @@ def test_compiled_kernel_refuses_a_mesh(flow_so):
     from repro.launch.mesh import make_mesh
     with pytest.raises(ValueError, match="no mesh route"):
         sample(flow_so, 16, seed=0, mesh=make_mesh((1, 1)), impl="pallas")
+
+
+# ---------------------------------------------------------------------------
+# the default impl: platform, mesh and shape decide; explicit choices win
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("platform,p,out,mesh,depth,want", [
+    ("tpu", 533, 533, False, 7, "pallas"),     # pion generation
+    ("tpu", 368, 1, False, 7, "pallas"),       # single-output forests
+    ("tpu", 2, 2, False, 3, "pallas"),         # a small table
+    ("cpu", 533, 533, False, 7, "xla"),
+    ("gpu", 533, 533, False, 7, "xla"),
+    ("tpu", 533, 533, True, 7, "xla"),         # GSPMD cannot split Mosaic
+    ("cpu", 533, 533, True, 7, "xla"),
+    ("tpu", 40000, 40000, False, 7, "xla"),    # blocks over the VMEM budget
+    ("tpu", 64, 64, False, 14, "xla"),         # path matrix over the budget
+])
+def test_default_impl_rule(platform, p, out, mesh, depth, want):
+    m = make_mesh((1, 1)) if mesh else None
+    assert default_impl(platform, p, out, m, depth) == want
+
+
+class _Spy:
+    """Stands in for the traversal (or the whole solve) and records the
+    impl each call resolved to."""
+
+    def __init__(self, result):
+        self.impls, self._result = [], result
+
+    def __call__(self, *args, **kw):
+        self.impls.append(kw["impl"])
+        return self._result(*args, **kw)
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The default backend reads as a TPU; the traversal never runs."""
+    monkeypatch.delenv("REPRO_TREE_PREDICT_IMPL", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _spy_solve(monkeypatch, art):
+    spy = _Spy(lambda *a, **kw: jnp.zeros((art.n_y, kw["m"], art.p)))
+    monkeypatch.setattr(sampling, "_solve_all_classes", spy)
+    return spy
+
+
+def test_sample_defaults_to_the_kernel_on_a_tpu(flow_so, monkeypatch, on_tpu):
+    spy = _spy_solve(monkeypatch, flow_so)
+    sample(flow_so, 40, seed=0)
+    # under a mesh the default is xla, and nothing raises
+    sample(flow_so, 40, seed=0, mesh=make_mesh((1, 1)))
+    assert spy.impls == ["pallas", "xla"]
+
+
+def test_explicit_choices_beat_the_default(flow_so, monkeypatch, on_tpu):
+    spy = _spy_solve(monkeypatch, flow_so)
+    sample(flow_so, 40, seed=0, impl="xla")
+    art_x = dataclasses.replace(
+        flow_so, config=dataclasses.replace(flow_so.config,
+                                            predict_impl="xla"))
+    sample(art_x, 40, seed=0)
+    monkeypatch.setenv("REPRO_TREE_PREDICT_IMPL", "xla")
+    sample(flow_so, 40, seed=0)
+    assert spy.impls == ["xla", "xla", "xla"]
+    # an explicit kernel under a mesh still has no route
+    with pytest.raises(ValueError, match="no mesh route"):
+        sample(flow_so, 16, seed=0, mesh=make_mesh((1, 1)), impl="pallas")
+
+
+def test_sample_defaults_to_xla_on_the_cpu(flow_so, monkeypatch):
+    monkeypatch.delenv("REPRO_TREE_PREDICT_IMPL", raising=False)
+    spy = _spy_solve(monkeypatch, flow_so)
+    sample(flow_so, 40, seed=0)
+    assert spy.impls == ["xla"]
+
+
+def test_impute_and_forest_predict_take_the_default(flow_so, moons,
+                                                    monkeypatch, on_tpu):
+    X, y = moons
+    Xm = X[:12].copy()
+    Xm[:, 1] = np.nan
+    lab = np.repeat(np.asarray(flow_so.classes), 6)[:12]
+    spy = _Spy(lambda x, *a, **kw: jnp.zeros_like(x))
+    monkeypatch.setattr(imputation, "predict_forest", spy)
+    impute(flow_so, Xm, lab, seed=2, refine_rounds=1)
+    assert spy.impls and set(spy.impls) == {"pallas"}
+    spy.impls.clear()
+    impute(flow_so, Xm, lab, seed=2, refine_rounds=1, impl="xla")
+    assert set(spy.impls) == {"xla"}
+
+    core = _Spy(lambda x, *a, **kw: x)
+    monkeypatch.setattr(ops, "_forest_predict", core)
+    f = flow_so
+    args = (jnp.zeros((8, f.p)), f.feat[0, 0, 0], f.thr_val[0, 0, 0],
+            f.leaf[0, 0, 0], f.config.max_depth)
+    ops.forest_predict(*args)
+    monkeypatch.setenv("REPRO_TREE_PREDICT_IMPL", "xla")
+    ops.forest_predict(*args)
+    assert core.impls == ["pallas", "xla"]
