@@ -129,7 +129,8 @@ def run(ctx) -> dict:
         "checks": checks,
         "facts": {"calls": len(calls), "rows_computed": rows_done,
                   "calls_traced": traced or len(calls),
-                  "wall_s": wall, "chips": len(ctx.devices),
+                  "wall_s": wall, "call_s": call_s,
+                  "chips": len(ctx.devices),
                   "steps": cfg["n_t"] - 1, "trees": cfg["n_trees"],
                   "depth": cfg["max_depth"], "p": cfg["p"],
                   "classes": cfg["classes_held"],

@@ -5,7 +5,9 @@
 yet proved) into ``dst`` beside a link to the program's ``src/``, and
 shrinks every configuration and cell through the same files a later
 change would edit: widths, scale and traffic all cut until a run takes
-seconds on the CPU. Nothing in the harness knows it is tiny.
+seconds on the CPU. A config without ``cuts`` keeps none, and a job that
+``JOB_CUTS`` does not know runs at the tiny scale uncut. Nothing in the
+harness knows it is tiny.
 """
 from __future__ import annotations
 
@@ -39,16 +41,21 @@ def edit_json(path: Path, fn) -> None:
     path.write_text(json.dumps(obj, indent=1))
 
 
-def tiny_tree(dst: Path) -> Path:
-    dst = Path(dst)
+def tiny_tree(dst: Path, source: Path = REPO) -> Path:
+    """The tiny copy in ``dst`` of the benchmark in ``source`` (this
+    repository's by default), beside a link to this repository's program."""
+    dst, source = Path(dst), Path(source)
+    dst.mkdir(parents=True, exist_ok=True)
     (dst / "src").symlink_to(REPO / "src")
-    shutil.copy(REPO / "BENCHMARK.json", dst / "BENCHMARK.json")
-    shutil.copytree(REPO / "chipbench", dst / "chipbench",
+    shutil.copy(source / "BENCHMARK.json", dst / "BENCHMARK.json")
+    shutil.copytree(source / "chipbench", dst / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     for cfg in (dst / "chipbench" / "configs").glob("*.json"):
         def shrink(c):
             c.update(TINY_CUTS)
-            c["cuts"] = {job: dict(JOB_CUTS[job]) for job in c["cuts"]}
+            if "cuts" in c:     # a job without a tiny cut runs uncut
+                c["cuts"] = {job: dict(JOB_CUTS.get(job, {}))
+                             for job in c["cuts"]}
         edit_json(cfg, shrink)
     for cell, traffic in TRAFFIC.items():
         edit_json(dst / "chipbench" / "workloads" / f"{cell}.json",

@@ -34,9 +34,50 @@ def tiny(tmp_path_factory):
     return tiny_tree(tmp_path_factory.mktemp("bench"))
 
 
+# a cell on several chips is rehearsed in a child process that sees as many
+# CPU devices; the parent's JAX has already fixed its own device count
+_CHILD = """
+import json, sys
+from pathlib import Path
+sys.path.insert(0, sys.argv[1])
+from chipbench import run as harness
+res = harness.run(Path(sys.argv[2]), sys.argv[3], int(sys.argv[4]),
+                  float(sys.argv[5]), sys.argv[6] == "1", platform=None,
+                  compile_cache=False)
+print(json.dumps(res))
+"""
+
+
 def rehearse(root, cell, trace=False, seconds=0.5):
-    return harness.run(root, cell, SEED, seconds, trace, platform=None,
-                       compile_cache=False)
+    bench = harness.benchmark(Path(root))
+    chips = next(w["chips"] for w in bench["workloads"] if w["name"] == cell)
+    if chips == 1:
+        return harness.run(root, cell, SEED, seconds, trace, platform=None,
+                           compile_cache=False)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={chips}")
+    out = subprocess.run([sys.executable, "-c", _CHILD, str(REPO), str(root),
+                          cell, str(SEED), str(seconds), str(int(trace))],
+                         env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def assert_contract(res, cell, bench):
+    """The result line of a run of ``cell`` keeps the contract."""
+    assert list(res)[-1] == "checks"
+    assert res["correct"] is True, res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    e2e = [m["name"] for m in bench["end_to_end"]
+           if "workloads" not in m or cell in m["workloads"]]
+    assert sorted(res["metrics"]) == sorted(e2e)
+    for m in res["metrics"].values():
+        assert math.isfinite(m["value"]) and m["value"] >= 0
+    assert set(res["device"]) >= {"platform", "kind", "count",
+                                  "memory_peak_bytes"}
+    chips = next(w["chips"] for w in bench["workloads"] if w["name"] == cell)
+    assert res["device"]["count"] == chips
+    assert all(set(c) == {"value", "limit"} for c in res["checks"].values())
 
 
 def test_benchmark_file_keeps_the_contract():
@@ -91,18 +132,7 @@ def test_held_out_cells_are_apart_from_the_benchmark():
 
 @pytest.mark.parametrize("cell", JOB_CELLS)
 def test_rehearsal_prints_the_contract(tiny, cell):
-    res = rehearse(tiny, cell)
-    assert list(res)[-1] == "checks"
-    assert res["correct"] is True, res["checks"]
-    assert res["attempted"] > 0 and res["failed"] == 0
-    e2e = [m["name"] for m in REHEARSAL["end_to_end"]
-           if "workloads" not in m or cell in m["workloads"]]
-    assert sorted(res["metrics"]) == sorted(e2e)
-    for m in res["metrics"].values():
-        assert math.isfinite(m["value"]) and m["value"] >= 0
-    assert set(res["device"]) >= {"platform", "kind", "count",
-                                  "memory_peak_bytes"}
-    assert all(set(c) == {"value", "limit"} for c in res["checks"].values())
+    assert_contract(rehearse(tiny, cell), cell, REHEARSAL)
 
 
 def test_traced_rehearsal_reads_the_span_metrics(tiny):
@@ -152,6 +182,67 @@ def test_a_new_config_cell_and_metric_run_by_name(tmp_path):
     res = harness.run(root, "calo_other.generate", 5, 0.3, True,
                       platform=None, compile_cache=False)
     assert res["metrics"]["gen.calls"]["value"] >= 1
+
+
+def test_a_four_chip_cell_added_by_data_rehearses_on_four_cpu_devices(
+        tmp_path):
+    root = tiny_tree(tmp_path)
+    here = root / "chipbench"
+    shutil.copy(here / "configs" / "calo_pions.json",
+                here / "configs" / "calo_pions_x4.json")
+    edit_json(here / "configs" / "calo_pions_x4.json",
+              lambda c: c.update(name="calo_pions_x4"))
+    shutil.copy(here / "workloads" / "calo_pions.generate.json",
+                here / "workloads" / "calo_pions_x4.generate.json")
+    edit_json(here / "workloads" / "calo_pions_x4.generate.json",
+              lambda w: w.update(config="calo_pions_x4", chips=4))
+    cell = "calo_pions_x4.generate"
+
+    def add(bench):
+        bench["configs"].append({"name": "calo_pions_x4", "source": "x",
+                                 "file": "chipbench/configs/"
+                                         "calo_pions_x4.json",
+                                 "reduced": [], "why": "x"})
+        bench["workloads"].append({"name": cell, "config": "calo_pions_x4",
+                                   "traffic": "generate", "chips": 4,
+                                   "why": "x"})
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if "calo_pions.generate" in m.get("workloads", []):
+                m["workloads"].append(cell)
+    edit_json(root / "BENCHMARK.json", add)
+    bench = harness.benchmark(root)
+    assert_contract(rehearse(root, cell), cell, bench)
+    # the same cell in this process, which sees one device, is refused
+    with pytest.raises(harness.Refused, match="needs 4 chips"):
+        harness.run(root, cell, SEED, 0.3, False, platform=None,
+                    compile_cache=False)
+
+
+def test_tiny_tree_shrinks_configs_with_unknown_jobs_or_no_cuts(tmp_path):
+    src = tmp_path / "src_tree"
+    src.mkdir()
+    shutil.copy(REPO / "BENCHMARK.json", src / "BENCHMARK.json")
+    shutil.copytree(REPO / "chipbench", src / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    configs = src / "chipbench" / "configs"
+    shutil.copy(configs / "calo_pions.json", configs / "calo_uncut.json")
+    edit_json(configs / "calo_uncut.json", lambda c: c.pop("cuts"))
+    shutil.copy(configs / "calo_pions.json", configs / "calo_sharded.json")
+    edit_json(configs / "calo_sharded.json", lambda c: c["cuts"].update(
+        generate_sharded={"classes_held": 4, "n_t": 50}))
+    import chipbench_tiny
+    root = chipbench_tiny.tiny_tree(tmp_path / "tiny", source=src)
+    here = root / "chipbench" / "configs"
+    uncut = json.loads((here / "calo_uncut.json").read_text())
+    sharded = json.loads((here / "calo_sharded.json").read_text())
+    assert "cuts" not in uncut and uncut["p"] == chipbench_tiny.TINY_CUTS["p"]
+    assert sharded["cuts"] == {"generate": chipbench_tiny.JOB_CUTS["generate"],
+                               "generate_sharded": {}}
+    assert sharded["n_t"] == chipbench_tiny.TINY_CUTS["n_t"]
+    # a job the tiny cuts do not know runs at the tiny scale, uncut
+    cfg = {k: v for k, v in sharded.items() if k != "cuts"}
+    cfg.update(sharded["cuts"]["generate_sharded"])
+    assert cfg["classes_held"] == 15 and cfg["n_t"] == 4
 
 
 def _cli(cwd, *args):
